@@ -1,4 +1,4 @@
-"""llama3.2-3b — small llama3 [hf:meta-llama/Llama-3.2-1B family card].
+"""llama3.2-3b — small llama3 [hf:meta-llama/Llama-3.2-3B config.json].
 
 28 layers, d_model 3072, 24 heads (kv=8), d_ff 8192, vocab 128256.
 SwiGLU, RMSNorm, rope theta 500k, tied embeddings.
@@ -8,7 +8,7 @@ from repro.configs.base import ArchConfig, SplitConfig
 CONFIG = ArchConfig(
     name="llama3.2-3b",
     family="dense",
-    source="hf:meta-llama/Llama-3.2-1B",
+    source="hf:meta-llama/Llama-3.2-3B",
     n_layers=28,
     d_model=3072,
     n_heads=24,

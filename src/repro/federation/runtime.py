@@ -336,7 +336,21 @@ def spawn_owner_worker(spec: OwnerWorkerSpec, *, owner=None, tap=None,
     (its ``endpoint`` is the scientist's end of the party boundary).
     ``dedup`` turns on seq-based duplicate drop on the parent's receive
     path — the supervised fit path uses it so a restarted worker's
-    replayed frames are idempotent."""
+    replayed frames are idempotent.
+
+    Each worker initializes its own JAX backend, so the workers can only
+    share the host's CPU: an accelerator held by this (parent) process
+    cannot be opened again by a child, which would fail or hang.  On any
+    other backend this raises before spawning."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"backend='process' cannot spawn owner {spec.name!r}: this "
+            f"process holds the {jax.default_backend()} device, and a "
+            "spawned owner worker cannot open it. Use backend='queue' or "
+            "'direct' on an accelerator (owners on their own chips is "
+            "ROADMAP item R5).")
     return _spawn(spec.name, owner_worker_main, spec, owner=owner,
                   tap=tap, dedup=dedup)
 

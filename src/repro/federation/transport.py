@@ -33,9 +33,9 @@ Cut-payload codecs live here too (``get_codec``): the only bytes that
 cross the boundary are cut activations and cut gradients, so shrinking
 them is the protocol's one compression lever (Secure Forward Aggregation,
 Cai et al. 2022, quantizes the same tensor).  ``fp16`` is a plain
-down-cast; ``int8`` is per-row symmetric quantization fused with wire
-packing in one Pallas kernel pass (``repro/kernels/quantize``): the wire
-payload is a single ``(rows, K+4)`` byte frame, values + bitcast scale.
+down-cast; ``int8`` is per-row symmetric quantization in a Pallas
+kernel (``repro/kernels/quantize``), packed in the same jitted program
+into a single ``(rows, K+4)`` byte frame, values + bitcast scale.
 """
 from __future__ import annotations
 
@@ -537,8 +537,8 @@ class FP16Codec(Codec):
 
 class Int8Codec(Codec):
     """Per-row symmetric int8 (scale = absmax/127 over the last axis),
-    quantized *and* wire-packed in one Pallas pass
-    (``repro/kernels/quantize.quantize_pack_int8``): the payload is a
+    quantized by a Pallas kernel and wire-packed in the same jitted
+    program (``repro/kernels/quantize.quantize_pack_int8``): the payload is a
     single ``(rows, K+4)`` uint8 frame — K int8 values plus the
     little-endian f32 scale bitcast into the trailing 4 bytes of each
     row.  Decodes to float32 (consumers cast to their compute dtype)."""

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 NEG_INF = -2.0 ** 30
 
 
@@ -126,7 +124,7 @@ def flash_attention_flat(q, k, v, *, kind: str = "causal", window: int = 0,
             pltpu.VMEM((bq,), jnp.float32),       # l
             pltpu.VMEM((bq, hd), jnp.float32),    # acc
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _cut_kernel(z_ref, w_ref, o_ref, acc_ref, *, combine: str, n_owners: int,
                 inv_p: float):
@@ -83,7 +81,7 @@ def cut_fusion_raw(z, w, *, combine: str = "concat",
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, c: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nm * bm, nn * bn), z.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(z, w[:1] if combine in ("sum", "mean") else w)

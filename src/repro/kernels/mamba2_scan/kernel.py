@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _ssd_kernel(x_ref, a_ref, dt_ref, b_ref, c_ref, y_ref, state_out_ref,
                 state_ref, *, L: int):
@@ -103,7 +101,7 @@ def ssd_scan_raw(x, a, dt, B_in, C_in, *, chunk: int = 128,
             jax.ShapeDtypeStruct((Bb, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, a, dt, B_in, C_in)

@@ -12,8 +12,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _quantize_kernel(x_ref, q_ref, s_ref):
@@ -23,23 +22,6 @@ def _quantize_kernel(x_ref, q_ref, s_ref):
     q = jnp.clip(jnp.round(x / scale), -127.0, 127.0)
     q_ref[...] = q.astype(jnp.int8)
     s_ref[...] = scale.astype(jnp.float32)
-
-
-def _quantize_pack_kernel(x_ref, out_ref):
-    """Quantize a (bm, K) row block AND lay it out wire-ready in the same
-    VMEM pass: ``out[:, :K]`` are the int8 values bitcast to uint8,
-    ``out[:, K:K+4]`` are the per-row f32 scales bitcast to their four
-    (little-endian) bytes.  The float activation never returns to HBM and
-    no second packing pass touches the quantized values."""
-    x = x_ref[...].astype(jnp.float32)                    # (bm, K)
-    absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)  # (bm, 1)
-    scale = jnp.maximum(absmax, 1e-12) / 127.0
-    q = jnp.clip(jnp.round(x / scale), -127.0, 127.0).astype(jnp.int8)
-    k = q.shape[-1]
-    out_ref[:, :k] = jax.lax.bitcast_convert_type(q, jnp.uint8)
-    sbytes = jax.lax.bitcast_convert_type(
-        scale.astype(jnp.float32), jnp.uint8)              # (bm, 1, 4)
-    out_ref[:, k:] = sbytes.reshape(sbytes.shape[0], 4)
 
 
 def quantize_int8_raw(x, *, block_m: int = 256, interpret: bool = False):
@@ -58,7 +40,7 @@ def quantize_int8_raw(x, *, block_m: int = 256, interpret: bool = False):
                    pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((nm * bm, K), jnp.int8),
                    jax.ShapeDtypeStruct((nm * bm, 1), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)
@@ -69,22 +51,14 @@ def quantize_pack_int8_raw(x, *, block_m: int = 256,
                            interpret: bool = False):
     """x: (T, K) float.  Returns the wire frame: a uint8 (T, K+4) array
     whose first K columns are the per-row symmetric int8 values and whose
-    trailing 4 columns are the little-endian bytes of the f32 row scale —
-    quantization and wire packing fused into one pass (the transport's
-    ``int8`` codec ships this buffer as-is)."""
-    T, K = x.shape
-    bm = min(block_m, T)
-    nm = -(-T // bm)
-    if nm * bm - T:
-        x = jnp.pad(x, ((0, nm * bm - T), (0, 0)))
-    out = pl.pallas_call(
-        _quantize_pack_kernel,
-        grid=(nm,),
-        in_specs=[pl.BlockSpec((bm, K), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bm, K + 4), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nm * bm, K + 4), jnp.uint8),
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel",)),
-        interpret=interpret,
-    )(x)
-    return out[:T]
+    trailing 4 columns are the little-endian bytes of the f32 row scale
+    (the transport's ``int8`` codec ships this buffer as-is).
+
+    The kernel quantizes; the byte packing is plain XLA around it.  The
+    TPU's Mosaic compiler refuses a bitwidth-changing bitcast (f32 ->
+    4 x uint8) inside a kernel, and the 4-byte scale column would be an
+    unaligned lane store, so neither happens in VMEM."""
+    q, scale = quantize_int8_raw(x, block_m=block_m, interpret=interpret)
+    qb = jax.lax.bitcast_convert_type(q, jnp.uint8)
+    sb = jax.lax.bitcast_convert_type(scale, jnp.uint8)    # (T, 1, 4)
+    return jnp.concatenate([qb, sb.reshape(q.shape[0], 4)], axis=-1)

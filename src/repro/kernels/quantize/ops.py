@@ -32,8 +32,8 @@ def _quantize_pack_jit(x, *, block_m: int, interpret: bool):
 
 def quantize_pack_int8(x, *, block_m: int = 256, interpret=None):
     """x: (T, K) float.  Returns the uint8 (T, K+4) wire frame: int8
-    values + bitcast little-endian f32 row scale, fused in one kernel
-    pass (no separate pack step touches the quantized buffer)."""
+    values + bitcast little-endian f32 row scale, quantized by the
+    kernel and packed in the same jitted program."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _quantize_pack_jit(x, block_m=block_m, interpret=interpret)

@@ -18,7 +18,7 @@ def dequantize_int8_ref(q, scale):
 
 
 def quantize_pack_int8_ref(x):
-    """Oracle for the fused quantize+pack kernel: uint8 (T, K+4) wire
+    """Oracle for the quantize+pack wire frame: uint8 (T, K+4) wire
     frame — int8 values bitcast to uint8 plus the 4 little-endian bytes
     of the f32 row scale."""
     import jax

@@ -645,9 +645,9 @@ class ServingEngine:
 
         try:
             self._continuous_loop(out, caches, slots, results, gen, tok_np)
+        except (QueueFull, jax.errors.JaxRuntimeError):
+            raise
         except (RuntimeError, OSError) as e:
-            if isinstance(e, QueueFull):
-                raise
             self._fail_pending(e, out, slots, results)
 
         self.stats["wall_s"] += time.time() - t0
@@ -739,7 +739,9 @@ class ServingEngine:
     def run(self) -> Dict[int, Result]:
         """Drain the queue; returns {request_id: Result}.  Requests that
         hit a transport/runtime fault mid-flight come back with
-        ``Result.error`` set instead of raising (degraded service)."""
+        ``Result.error`` set instead of raising (degraded service).  A
+        device fault (``jax.errors.JaxRuntimeError``: out of memory, a
+        failed compile) is not a per-request condition and raises."""
         if self.scheduler == "continuous":
             return self._run_continuous()
         out: Dict[int, Result] = {}
@@ -750,6 +752,8 @@ class ServingEngine:
                     out[res.rid] = res
             except (RuntimeError, OSError) as e:
                 self._queue = wave + self._queue   # wave died unserved
+                if isinstance(e, jax.errors.JaxRuntimeError):
+                    raise
                 self._fail_pending(e, out)
         return out
 

@@ -19,6 +19,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.data import make_token_dataset
 from repro.federation import VerticalSession, sequence_parties
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -63,4 +64,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -47,16 +47,9 @@ class ShardingRules:
 
 
 def abstract_mesh(axis_sizes, axis_names):
-    """``jax.sharding.AbstractMesh`` across the signature change: the
-    0.4.x line takes one ``((name, size), ...)`` tuple, jax >= 0.5 takes
-    ``(sizes, names)``.  Spec construction only consults ``mesh.shape`` /
-    ``axis_names``, so no devices are needed either way."""
-    try:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(axis_sizes),
-                                         tuple(axis_names))
+    """A device-free ``jax.sharding.AbstractMesh``: spec construction
+    only consults ``mesh.shape`` / ``axis_names``."""
+    return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def make_rules(mesh, cfg, **kw) -> ShardingRules:

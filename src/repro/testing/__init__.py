@@ -1,2 +1,1 @@
-from repro.testing.hypo import (HAVE_HYPOTHESIS, given,  # noqa: F401
-                                settings, strategies)
+from repro.testing.hypo import given, settings, strategies  # noqa: F401

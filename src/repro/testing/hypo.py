@@ -1,181 +1,14 @@
-"""Property-testing shim: real ``hypothesis`` when installed, else a
-deterministic random-sampling fallback.
+"""Property-testing entry point: tests import ``given / settings /
+strategies`` from here instead of from ``hypothesis`` directly.
 
-Tests import ``given / settings / strategies`` from here instead of from
-``hypothesis`` directly.  On machines with hypothesis installed (it is
-listed in ``requirements-dev.txt``) this module is a pure re-export and
-behavior is identical.  Offline CI images that lack it get a minimal
-mini-implementation of the strategy surface the repo's tests use
-(``integers, floats, booleans, text, binary, sampled_from, just, lists,
-sets, tuples``): each test runs ``max_examples`` random examples drawn
-from a per-test deterministic seed (crc32 of the test's qualname), and a
-failing example is re-raised with the generated arguments attached.  No
-shrinking — the first falsifying example is reported verbatim.
+Importing this module loads and selects the repo's hypothesis profile,
+once: no per-example deadline, because the first example of a property
+test pays for JAX tracing and compilation, which takes far longer than
+hypothesis's default 200 ms.
 """
 from __future__ import annotations
 
-try:  # pragma: no cover - depends on environment
-    from hypothesis import HealthCheck, assume, given, settings  # noqa
-    from hypothesis import strategies  # noqa: F401
+from hypothesis import given, settings, strategies  # noqa: F401
 
-    HAVE_HYPOTHESIS = True
-except ImportError:
-    HAVE_HYPOTHESIS = False
-
-    import functools
-    import random
-    import string
-    import zlib
-
-    class _Unsatisfied(Exception):
-        pass
-
-    def assume(condition):
-        if not condition:
-            raise _Unsatisfied
-        return True
-
-    class HealthCheck:  # attribute sink: settings(suppress_health_check=..)
-        all = staticmethod(lambda: ())
-        too_slow = data_too_large = filter_too_much = None
-
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def example_from(self, rng: random.Random):
-            return self._draw(rng)
-
-        def map(self, f):
-            return _Strategy(lambda rng: f(self._draw(rng)))
-
-        def filter(self, pred, _tries: int = 100):
-            def draw(rng):
-                for _ in range(_tries):
-                    v = self._draw(rng)
-                    if pred(v):
-                        return v
-                raise _Unsatisfied
-            return _Strategy(draw)
-
-    class strategies:
-        """The subset of ``hypothesis.strategies`` the repo's tests use."""
-
-        @staticmethod
-        def integers(min_value=None, max_value=None):
-            lo = -(2 ** 31) if min_value is None else min_value
-            hi = 2 ** 31 if max_value is None else max_value
-            return _Strategy(lambda rng: rng.randint(lo, hi))
-
-        @staticmethod
-        def floats(min_value=None, max_value=None, **_kw):
-            lo = -1e9 if min_value is None else min_value
-            hi = 1e9 if max_value is None else max_value
-            return _Strategy(lambda rng: rng.uniform(lo, hi))
-
-        @staticmethod
-        def booleans():
-            return _Strategy(lambda rng: rng.random() < 0.5)
-
-        @staticmethod
-        def sampled_from(seq):
-            seq = list(seq)
-            return _Strategy(lambda rng: rng.choice(seq))
-
-        @staticmethod
-        def just(value):
-            return _Strategy(lambda rng: value)
-
-        @staticmethod
-        def text(alphabet=None, min_size=0, max_size=20):
-            chars = (list(alphabet) if alphabet is not None
-                     else list(string.ascii_letters + string.digits +
-                               string.punctuation + " "))
-            def draw(rng):
-                n = rng.randint(min_size, max_size)
-                return "".join(rng.choice(chars) for _ in range(n))
-            return _Strategy(draw)
-
-        @staticmethod
-        def binary(min_size=0, max_size=20):
-            def draw(rng):
-                n = rng.randint(min_size, max_size)
-                return bytes(rng.randrange(256) for _ in range(n))
-            return _Strategy(draw)
-
-        @staticmethod
-        def lists(elements, min_size=0, max_size=20, unique=False):
-            def draw(rng):
-                n = rng.randint(min_size, max_size)
-                if not unique:
-                    return [elements.example_from(rng) for _ in range(n)]
-                out, seen = [], set()
-                for _ in range(20 * n + 100):
-                    v = elements.example_from(rng)
-                    if v not in seen:
-                        seen.add(v)
-                        out.append(v)
-                    if len(out) == n:
-                        break
-                return out
-            return _Strategy(draw)
-
-        @staticmethod
-        def sets(elements, min_size=0, max_size=20):
-            def draw(rng):
-                n = rng.randint(min_size, max_size)
-                out = set()
-                for _ in range(20 * n + 100):
-                    out.add(elements.example_from(rng))
-                    if len(out) == n:
-                        break
-                return out
-            return _Strategy(draw)
-
-        @staticmethod
-        def tuples(*elems):
-            return _Strategy(
-                lambda rng: tuple(e.example_from(rng) for e in elems))
-
-    def settings(max_examples=100, deadline=None, **_kw):
-        """Record ``max_examples``; works above or below ``@given``."""
-        def deco(fn):
-            fn._shim_settings = {"max_examples": max_examples}
-            return fn
-        return deco
-
-    import inspect
-
-    def given(*strats, **kwstrats):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                cfg = (getattr(wrapper, "_shim_settings", None)
-                       or getattr(fn, "_shim_settings", None) or {})
-                n = cfg.get("max_examples", 100)
-                rng = random.Random(zlib.crc32(fn.__qualname__.encode()))
-                ran = 0
-                for i in range(n * 5):
-                    if ran >= n:
-                        break
-                    try:
-                        vals = [s.example_from(rng) for s in strats]
-                        kws = {k: s.example_from(rng)
-                               for k, s in kwstrats.items()}
-                    except _Unsatisfied:
-                        continue
-                    try:
-                        fn(*args, *vals, **{**kwargs, **kws})
-                        ran += 1
-                    except _Unsatisfied:
-                        continue
-                    except Exception as e:
-                        raise AssertionError(
-                            f"falsifying example #{ran}: args={vals!r} "
-                            f"kwargs={kws!r}") from e
-            # strategies supply every parameter: hide the original
-            # signature so pytest doesn't mistake params for fixtures
-            del wrapper.__wrapped__
-            wrapper.__signature__ = inspect.Signature()
-            return wrapper
-        return deco
+settings.register_profile("repro", deadline=None)
+settings.load_profile("repro")

@@ -58,7 +58,7 @@ def test_token_dataset_has_learnable_structure():
 
 
 @given(st.integers(10, 100), st.integers(1, 16))
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20)
 def test_batches_partition_without_duplication(n, bs):
     data = {"x": np.arange(n)}
     seen = []

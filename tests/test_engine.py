@@ -151,3 +151,26 @@ def test_degraded_service_per_request_errors(scheduler, monkeypatch):
     rid = eng.submit(rng.integers(0, cfg.vocab, 32))
     ok = eng.run()
     assert ok[rid].error is None and len(ok[rid].generated) == 2
+
+
+@pytest.mark.parametrize("scheduler", ["wave", "continuous"])
+def test_device_fault_raises(scheduler, monkeypatch):
+    """A device fault (JaxRuntimeError: out of memory, failed compile) is
+    not a degraded per-request result: ``run`` raises it."""
+    cfg = get_config("llama3.2-3b", reduced=True)
+    model = SplitModel(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = ServingEngine(model, params, batch_slots=2, ctx_len=32,
+                        max_new=2, scheduler=scheduler)
+    rng = np.random.default_rng(4)
+    for _ in range(3):
+        eng.submit(rng.integers(0, cfg.vocab, 32))
+
+    def boom(*a):
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    hook = "_run_wave" if scheduler == "wave" else "_continuous_loop"
+    monkeypatch.setattr(eng, hook, boom)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE"):
+        eng.run()
+    assert eng.stats["failed_requests"] == 0

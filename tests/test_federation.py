@@ -25,7 +25,7 @@ RNG = np.random.default_rng(0)
 
 
 @given(st.integers(1, 16), st.integers(1, 12), st.sampled_from([1, 2, 4]))
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 def test_feature_layout_round_trips(batch, width_per_owner, n_owners):
     x = RNG.normal(size=(batch, width_per_owner * n_owners))
     slices = partition_features(x, n_owners)
@@ -37,7 +37,7 @@ def test_feature_layout_round_trips(batch, width_per_owner, n_owners):
 
 
 @given(st.integers(1, 8), st.integers(1, 16), st.sampled_from([1, 2, 4]))
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 def test_sequence_layout_round_trips(batch, s_per_owner, n_owners):
     toks = RNG.integers(0, 1000, (batch, s_per_owner * n_owners))
     ot = batching.sequence_owner_slices(toks, n_owners)

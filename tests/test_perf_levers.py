@@ -73,10 +73,11 @@ def test_ring_cache_decode_matches_full_cache():
 
 def test_microbatch_accumulation_matches_single_batch():
     import jax
+    from repro.launch.mesh import make_host_mesh
     from repro.launch.steps import build, make_optimizer
     from repro.sharding.specs import make_rules
     cfg = get_config("llama3.2-3b", reduced=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     shape = ShapeConfig("t", 32, 4, "train")
     rules = make_rules(mesh, cfg)
     model = SplitModel(cfg)

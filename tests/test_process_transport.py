@@ -468,6 +468,40 @@ def test_eight_owner_uneven_widths_end_to_end():
 # ---------------------------------------------------------------------------
 
 
+def test_process_fit_refuses_a_held_accelerator(monkeypatch):
+    """On an accelerator backend the parent holds the device, so a
+    spawned owner worker could not open it: ``backend="process"`` fails
+    at once with an error naming the cause, and spawns nothing."""
+    import jax
+
+    from repro.federation import runtime
+    s = _mnist_session(200)
+    s.resolve(group=GROUP)
+    s.build(CONFIG)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(runtime, "_spawn", lambda *a, **k: pytest.fail(
+        "spawned a worker on an accelerator backend"))
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError, match="holds the tpu device.*R5"):
+        s.fit(mode="split", epochs=1, batch_size=64, backend="process",
+              verbose=False)
+    assert time.monotonic() - t0 < 30.0
+
+
+def test_psi_and_modexp_workers_never_load_jax():
+    """The spawn targets of the PSI server workers and the modexp pool
+    import a jax-free chain, so those children never initialize a JAX
+    backend (and cannot contend for an accelerator the parent holds)."""
+    import subprocess
+    import sys
+    code = ("import sys, repro.federation.runtime, repro.core.psi, "
+            "repro.federation.psi_transport, repro.core.modexp; "
+            "print('jax' in sys.modules)")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, check=True, timeout=120)
+    assert out.stdout.strip() == "False"
+
+
 @pytest.mark.slow
 def test_process_fit_owner_crash_surfaces_cleanly(monkeypatch):
     """A worker process that dies mid-step (chaos-injected on its first

@@ -18,7 +18,7 @@ GROUP = "modp512"  # fast test group; protocol identical to modp2048
 
 
 @given(st.sets(st.binary(min_size=1, max_size=32), min_size=1, max_size=200))
-@settings(max_examples=50, deadline=None)
+@settings(max_examples=50)
 def test_bloom_no_false_negatives(items):
     bf = BloomFilter.for_capacity(len(items), 1e-6)
     bf.add_all(items)
@@ -27,7 +27,7 @@ def test_bloom_no_false_negatives(items):
 
 
 @given(st.integers(min_value=1, max_value=500))
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20)
 def test_bloom_false_positive_rate(n):
     bf = BloomFilter.for_capacity(n, 1e-4)
     members = [f"member-{i}".encode() for i in range(n)]
@@ -76,7 +76,7 @@ def test_blinding_commutes():
 
 @given(st.sets(st.text(min_size=1, max_size=12), min_size=0, max_size=40),
        st.sets(st.text(min_size=1, max_size=12), min_size=0, max_size=40))
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20)
 def test_psi_equals_set_intersection(xs, ys):
     xs, ys = sorted(xs), sorted(ys)
     inter, _ = psi_intersect(xs, ys, group=GROUP)

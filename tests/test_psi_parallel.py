@@ -30,7 +30,7 @@ def _reset(client, server):
 @given(st.lists(st.text(min_size=1, max_size=10), min_size=0, max_size=60),
        st.lists(st.text(min_size=1, max_size=10), min_size=0, max_size=60),
        st.integers(1, 17))
-@settings(max_examples=12, deadline=None)
+@settings(max_examples=12)
 def test_chunked_round_bit_identical_to_serial(xs, ys, chunk):
     """Random uneven sets (duplicates allowed): every chunk size yields
     the exact same intersection list — same elements, same order, same
@@ -91,7 +91,7 @@ def test_memoized_blind_survives_engine_switch():
 
 @given(st.lists(st.text(min_size=1, max_size=8), min_size=0, max_size=40),
        st.lists(st.text(min_size=1, max_size=8), min_size=0, max_size=40))
-@settings(max_examples=10, deadline=None)
+@settings(max_examples=10)
 def test_noinv_and_bloom_modes_agree(xs, ys):
     """Both protocol variants (inverse-free double-blinded comparison vs
     Bloom-compressed unblinding) recover the same intersection, with the
@@ -199,7 +199,7 @@ def test_round_reports_bounded_inflight():
 
 @given(st.sets(st.binary(min_size=1, max_size=24), min_size=1, max_size=300),
        st.integers(1, 5))
-@settings(max_examples=15, deadline=None)
+@settings(max_examples=15)
 def test_sharded_bloom_no_false_negatives(items, shards):
     items = sorted(items)
     bf = ShardedBloom.for_capacity(len(items), 1e-6, n_shards=shards)

@@ -54,7 +54,7 @@ def _wire_round(xs, ys, *, mode="noinv", chunk_size=16, latency_s=0.0,
        st.lists(st.text(min_size=1, max_size=8), min_size=0, max_size=40),
        st.integers(1, 17),
        st.sampled_from(["noinv", "bloom"]))
-@settings(max_examples=8, deadline=None)
+@settings(max_examples=8)
 def test_wire_round_bit_identical_to_in_process(xs, ys, chunk, mode):
     """Random uneven sets (duplicates allowed), both protocol variants,
     any chunk size: the wire engine returns the exact intersection list

@@ -25,7 +25,7 @@ def _setup(n, keep, seed, n_owners=2):
 
 
 @given(st.integers(20, 120), st.floats(0.5, 1.0), st.integers(0, 10_000))
-@settings(max_examples=15, deadline=None)
+@settings(max_examples=15)
 def test_resolution_aligns_all_parties(n, keep, seed):
     ids, X, y, slices, sci, owners = _setup(n, keep, seed)
     s_al, o_al, stats = resolve(sci, owners, group=GROUP)
@@ -60,7 +60,7 @@ def test_duplicate_ids_rejected():
 
 
 @given(st.integers(1, 8), st.integers(1, 6), st.integers(0, 1000))
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 def test_partition_unpartition_roundtrip(n_owners, per_owner, seed):
     rng = np.random.default_rng(seed)
     x = rng.normal(size=(7, n_owners * per_owner)).astype(np.float32)

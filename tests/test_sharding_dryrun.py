@@ -19,14 +19,15 @@ from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.launch.steps import build
 from repro.launch import analysis
+from repro.launch.mesh import make_host_mesh
 from repro.sharding.specs import make_rules, named
 
 arch, kind, multi_pod = "%ARCH%", "%KIND%", %MULTI%
 cfg = get_config(arch, reduced=True)
 if multi_pod:
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_host_mesh(pod=2, data=2, model=2)
 else:
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(data=2, model=4)
 shape = ShapeConfig("t", 32, 4, kind)
 rules = make_rules(mesh, cfg)
 fn, args, specs, donate = build(cfg, shape, mesh, rules)

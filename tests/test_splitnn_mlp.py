@@ -125,7 +125,7 @@ def test_cut_layer_traffic_accounting():
 
 
 @given(st.integers(2, 4), st.sampled_from(["concat", "sum", "mean", "max"]))
-@settings(max_examples=8, deadline=None)
+@settings(max_examples=8)
 def test_n_owner_generalization(n_owners, combine):
     """The paper's future-work axis: >2 owners work out of the box."""
     import dataclasses

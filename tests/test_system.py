@@ -4,6 +4,7 @@ and the large-model split-training/serving drivers."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs.pyvertical_mnist import CONFIG as MNIST_CFG
 from repro.core import MLPSplitNN, make_split_train_step, resolve
@@ -50,3 +51,44 @@ def test_serve_launcher_generates():
                 "--ctx", "32", "--new", "5"])
     assert gen.shape == (2, 5)
     assert (gen >= 0).all()
+
+
+_CACHE_PROBE = r"""
+import json, os
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import DEFAULT_DIR, enable_compile_cache
+d = enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(8.0)).block_until_ready()
+print(json.dumps({"dir": d, "config": jax.config.jax_compilation_cache_dir,
+                  "default": str(DEFAULT_DIR)}))
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(from_env, tmp_path):
+    """The persistent compilation cache goes where
+    ``JAX_COMPILATION_CACHE_DIR`` says, and only there; without it, to
+    ``.jax_cache/`` at the checkout root.  Probed in a child process, so
+    this test process never turns the cache on."""
+    import json
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env_dir = tmp_path / "cache"
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                         capture_output=True, text=True, check=True,
+                         timeout=120)
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert got["default"] == str(root / ".jax_cache")
+    want = str(env_dir) if from_env else got["default"]
+    assert got["dir"] == got["config"] == want
+    if from_env:
+        assert any(env_dir.iterdir())       # the compile landed there

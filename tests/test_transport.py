@@ -145,7 +145,7 @@ def test_quantize_kernel_matches_ref():
 
 @given(st.lists(st.integers(1, 7), min_size=1, max_size=5),
        st.integers(0, 1000))
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 def test_uneven_partition_round_trips(sizes, seed):
     rng = np.random.default_rng(seed)
     width = sum(sizes)
@@ -339,7 +339,7 @@ def test_serving_through_transport_measures_cut_bytes():
 
 
 @given(st.sampled_from([2, 4]), st.integers(0, 3))
-@settings(max_examples=3, deadline=None)
+@settings(max_examples=3)
 def test_microbatched_split_matches_microbatched_oracle(micro, seed):
     """fit(mode="split", microbatches=M) — M GPipe cut exchanges in
     flight per channel — reproduces the microbatched joint oracle
@@ -459,7 +459,7 @@ def test_owner_thread_exception_surfaces(monkeypatch):
 
 
 def test_quantize_pack_kernel_matches_ref():
-    """The fused quantize+pack kernel emits the exact wire frame of the
+    """The quantize kernel plus packing emits the exact wire frame of the
     reference (int8 values bit-exact; packed f32 scales within float
     tolerance of the jnp oracle)."""
     from repro.kernels.quantize import (quantize_int8_ref,
