@@ -27,6 +27,8 @@ import numpy as np
 from repro.core.psi import DEFAULT_MODE, PSIClient, PSIServer
 from repro.core.resolution import VerticalDataset
 from repro.core.vertical import make_ids, partition_sequence
+from repro.federation.spans import (CUT_ENCODE, OWNER_CUT_GRAD,
+                                    OWNER_FWD_REQUEST, span)
 from repro.optim import apply_updates
 
 
@@ -357,25 +359,28 @@ class OwnerComputeEndpoint:
 
     def _ship_cut(self, out, seq: int, kind: str = "cut_activations"
                   ) -> None:
-        # segment programs may return (cut, aux): the scalar owner-local
-        # aux loss rides along for metric parity
-        cut, aux = out if isinstance(out, tuple) else (out, None)
-        if self.masker is not None:
-            # masked-sum wire format: {"mq": uint32 ring element}.
-            # Bypasses the codec — uniform ring bytes are incompressible
-            # and already 4 bytes/element, the f32 it replaces.
-            tag = (self.masker.step_tag(seq) if kind == "cut_activations"
-                   else self.masker.warmup_tag(seq))
-            payload = self.masker.encode(cut, tag)
-        else:
-            if self.cut_noise_std > 0.0 and kind == "cut_activations":
-                from repro.core.privacy import deterministic_cut_noise
-                cut = deterministic_cut_noise(
-                    cut, self.cut_noise_std, self.noise_seed, f"s{seq}")
-            payload = self.codec.encode(cut)
-        if aux is not None:
-            payload["aux"] = np.float32(np.asarray(aux).sum())
-        self.endpoint.send(kind, payload, seq=seq)
+        with span(CUT_ENCODE, party=self.owner.name, seq=seq):
+            # segment programs may return (cut, aux): the scalar
+            # owner-local aux loss rides along for metric parity
+            cut, aux = out if isinstance(out, tuple) else (out, None)
+            if self.masker is not None:
+                # masked-sum wire format: {"mq": uint32 ring element}.
+                # Bypasses the codec — uniform ring bytes are
+                # incompressible and already 4 bytes/element, the f32 it
+                # replaces.
+                tag = (self.masker.step_tag(seq)
+                       if kind == "cut_activations"
+                       else self.masker.warmup_tag(seq))
+                payload = self.masker.encode(cut, tag)
+            else:
+                if self.cut_noise_std > 0.0 and kind == "cut_activations":
+                    from repro.core.privacy import deterministic_cut_noise
+                    cut = deterministic_cut_noise(
+                        cut, self.cut_noise_std, self.noise_seed, f"s{seq}")
+                payload = self.codec.encode(cut)
+            if aux is not None:
+                payload["aux"] = np.float32(np.asarray(aux).sum())
+            self.endpoint.send(kind, payload, seq=seq)
 
     def _run_fwd(self, step: int, first_out=None) -> None:
         """Run + ship the microbatch forwards of ``step`` (params are
@@ -447,49 +452,53 @@ class OwnerComputeEndpoint:
             return True
         if msg.kind == "head_fwd":
             step = int(msg.seq)
-            self._plan[step] = self._stage(msg.payload["idx"])
-            if step == self.steps_done:
-                # all updates through step-1 applied — run now; otherwise
-                # the staged plan runs when the step-(t-1) update lands
-                self._run_fwd(step)
+            with span(OWNER_FWD_REQUEST, party=self.owner.name, seq=step):
+                self._plan[step] = self._stage(msg.payload["idx"])
+                if step == self.steps_done:
+                    # all updates through step-1 applied — run now;
+                    # otherwise the staged plan runs when the step-(t-1)
+                    # update lands
+                    self._run_fwd(step)
             return True
         if msg.kind == "cut_gradients":
             import jax
             import jax.numpy as jnp
             seq = int(msg.seq)
-            g = jnp.asarray(self.codec.decode(msg.payload))
-            x = self._inflight.pop(seq)
-            # grads accumulate at step-start params; ONE update per step
-            # on its last chunk (GPipe semantics — the exact full-batch
-            # step; with micro == 1 this degenerates to the one-shot
-            # update)
-            last = self._grads_seen + 1 == self.micro
-            nxt = self.steps_done + 1
-            if last and self._tail is not None and nxt in self._plan:
-                # fused fast path: final-chunk bwd + accumulate + update
-                # + next step's first forward, one compiled dispatch
-                self.params, self.opt_state, out = self._tail(
-                    self.params, self.opt_state, self._grad_acc, x, g,
-                    self.steps_done, self._plan[nxt][0])
-                self._grad_acc, self._grads_seen = None, 0
-                self.steps_done = nxt
-                self._run_fwd(nxt, out)
-            else:
-                grads = self.head_bwd(self.params, x, g)
-                self._grad_acc = grads if self._grad_acc is None else \
-                    jax.tree.map(lambda a, b: a + b, self._grad_acc,
-                                 grads)
-                self._grads_seen += 1
-                if last:
-                    self.params, self.opt_state = self._update(
-                        self.params, self.opt_state, self._grad_acc,
-                        self.steps_done)
+            with span(OWNER_CUT_GRAD, party=self.owner.name, seq=seq):
+                g = jnp.asarray(self.codec.decode(msg.payload))
+                x = self._inflight.pop(seq)
+                # grads accumulate at step-start params; ONE update per
+                # step on its last chunk (GPipe semantics — the exact
+                # full-batch step; with micro == 1 this degenerates to
+                # the one-shot update)
+                last = self._grads_seen + 1 == self.micro
+                nxt = self.steps_done + 1
+                if last and self._tail is not None and nxt in self._plan:
+                    # fused fast path: final-chunk bwd + accumulate +
+                    # update + next step's first forward, one compiled
+                    # dispatch
+                    self.params, self.opt_state, out = self._tail(
+                        self.params, self.opt_state, self._grad_acc, x, g,
+                        self.steps_done, self._plan[nxt][0])
                     self._grad_acc, self._grads_seen = None, 0
-                    self.steps_done += 1
-                    if self.steps_done in self._plan:
-                        self._run_fwd(self.steps_done)
-            if self.ack_steps:
-                self.endpoint.send("step_done", {}, seq=seq)
+                    self.steps_done = nxt
+                    self._run_fwd(nxt, out)
+                else:
+                    grads = self.head_bwd(self.params, x, g)
+                    self._grad_acc = grads if self._grad_acc is None \
+                        else jax.tree.map(lambda a, b: a + b,
+                                          self._grad_acc, grads)
+                    self._grads_seen += 1
+                    if last:
+                        self.params, self.opt_state = self._update(
+                            self.params, self.opt_state, self._grad_acc,
+                            self.steps_done)
+                        self._grad_acc, self._grads_seen = None, 0
+                        self.steps_done += 1
+                        if self.steps_done in self._plan:
+                            self._run_fwd(self.steps_done)
+                if self.ack_steps:
+                    self.endpoint.send("step_done", {}, seq=seq)
             return True
         if msg.kind == "heartbeat":
             # liveness probe (federation/supervisor.py): answering

@@ -49,6 +49,7 @@ Training modes:
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import queue as _queue
 import threading
@@ -66,16 +67,27 @@ from repro.core.modexp import ModexpPool
 from repro.core.psi import DEFAULT_CHUNK, DEFAULT_MODE, psi_round
 from repro.core.splitnn import (cut_layer_traffic, make_split_train_step,
                                 train_state_init)
-from repro.federation import batching, faults, transport
+from repro.federation import batching, faults, spans, transport
 from repro.federation.parties import (DataOwner, DataScientist,
                                       OwnerComputeEndpoint, PrivacyError)
 from repro.federation.registry import build_adapter
+from repro.federation.spans import SCIENTIST, span
 from repro.federation.supervisor import OwnerFailure, Supervisor
 from repro.federation.transport import FrameCorrupt
 
 
 def _scalars(m):
     return {k: float(v) for k, v in m.items()}
+
+
+def _read_scalars(m):
+    """``_scalars`` of the split loop: each read from the device in a
+    ``vfl.host_read`` span."""
+    out = {}
+    for k, v in m.items():
+        with span(spans.HOST_READ, party=SCIENTIST, bytes=v.nbytes):
+            out[k] = float(v)
+    return out
 
 
 def _tree_add(a, b):
@@ -773,17 +785,18 @@ class VerticalSession:
 
     def _train_bookkeeping(self, t, metrics, history, t0, *, epochs,
                            steps, steps_per_epoch, log_every, verbose,
-                           ckpt_dir, ckpt_every, sync):
+                           ckpt_dir, ckpt_every, sync, scalars=_scalars):
         """Per-step history/eval/print/checkpoint — shared by the
         microbatched joint oracle and the split loop.  ``sync`` makes
         ``self.params`` current (a transport barrier + reassembly for
         the split loop, a local reassembly for the oracle) before any
-        eval or checkpoint touches them."""
+        eval or checkpoint touches them; ``scalars`` reads the metrics
+        to the host.  ``t0`` is a ``time.perf_counter()`` reading."""
         if epochs is not None:
             if (t + 1) % steps_per_epoch:
                 return
             ep_i = (t + 1) // steps_per_epoch - 1
-            rec = {"epoch": ep_i, **_scalars(metrics)}
+            rec = {"epoch": ep_i, **scalars(metrics)}
             history["train"].append(rec)
             if len(self._eval_idx):
                 sync()
@@ -797,18 +810,19 @@ class VerticalSession:
                 print(f"epoch {ep_i:3d} " + " ".join(
                     f"{k}={v:.4f}" for k, v in rec.items()
                     if k != "epoch") + extra +
-                    f" ({time.time() - t0:.1f}s)")
+                    f" ({time.perf_counter() - t0:.1f}s)")
             if ckpt_dir and ckpt_every and (ep_i + 1) % ckpt_every == 0:
                 sync()
                 self.checkpoint(ckpt_dir, ep_i + 1)
         else:
-            rec = {"step": t, **_scalars(metrics)}
+            rec = {"step": t, **scalars(metrics)}
             history["train"].append(rec)
             if verbose and log_every and (t % log_every == 0
                                           or t == steps - 1):
                 print(f"step {t:5d} " + " ".join(
                     f"{k}={v:.4f}" for k, v in rec.items()
-                    if k != "step") + f" ({time.time() - t0:.1f}s)")
+                    if k != "step")
+                    + f" ({time.perf_counter() - t0:.1f}s)")
             if ckpt_dir and ckpt_every and (t + 1) % ckpt_every == 0:
                 sync()
                 self.checkpoint(ckpt_dir, t + 1)
@@ -880,7 +894,7 @@ class VerticalSession:
                            "trunk": tp}
 
         history: dict = {"train": [], "eval": []}
-        t0 = time.time()
+        t0 = time.perf_counter()
         metrics: dict = {}
 
         for t in range(total_steps):
@@ -1057,6 +1071,11 @@ class VerticalSession:
         way.  The LM adapter clips grads per-owner instead of across all
         heads, so it tracks the joint path within tolerance rather than
         exactly."""
+        # ``vfl.fit_start`` runs from here through owner spawn and the
+        # warm-up handshake; ``vfl.fit_end`` from the last step through
+        # the owners' stop and join
+        phase = contextlib.ExitStack()
+        phase.enter_context(span(spans.FIT_START, party=SCIENTIST))
         adapter = self.adapter
         if not getattr(adapter, "supports_split", False):
             raise ValueError(f"{type(adapter).__name__} does not support "
@@ -1249,9 +1268,10 @@ class VerticalSession:
         inflight: deque = deque()
 
         def send_fwd(idx, seq):
-            for ep in eps:
-                ep.send("head_fwd", {"idx": np.asarray(idx, np.int32)},
-                        seq=seq)
+            with span(spans.SEND_FWD, party=SCIENTIST, step=seq):
+                for ep in eps:
+                    ep.send("head_fwd", {"idx": np.asarray(idx, np.int32)},
+                            seq=seq)
             inflight.append(idx)
 
         def recv_chunk(seq):
@@ -1262,18 +1282,20 @@ class VerticalSession:
             the return is the reconstructed int32 SUM — the scientist
             never materializes a per-owner activation."""
             cuts, payloads, aux = [], [], 0.0
-            for ep, w in zip(eps, workers):
-                m = self._recv_from_owner(ep, w, "cut_activations",
-                                          timeout=timeout)
-                if m.seq != seq:
-                    raise RuntimeError(f"protocol desync: cut seq {m.seq} "
-                                       f"!= expected {seq}")
-                if masked:
-                    payloads.append(m.payload)
-                else:
-                    cuts.append(codec.decode(m.payload))
-                if "aux" in m.payload:
-                    aux += float(np.asarray(m.payload["aux"]).sum())
+            for owner, ep, w in zip(self.owners, eps, workers):
+                with span(spans.CUT_EXCHANGE, party=SCIENTIST,
+                          peer=owner.name, step=seq // M):
+                    m = self._recv_from_owner(ep, w, "cut_activations",
+                                              timeout=timeout)
+                    if m.seq != seq:
+                        raise RuntimeError(f"protocol desync: cut seq "
+                                           f"{m.seq} != expected {seq}")
+                    if masked:
+                        payloads.append(m.payload)
+                    else:
+                        cuts.append(codec.decode(m.payload))
+                    if "aux" in m.payload:
+                        aux += float(np.asarray(m.payload["aux"]).sum())
             if masked:
                 return jnp.asarray(masking.reconstruct(payloads)), aux
             return tuple(cuts), aux
@@ -1339,10 +1361,11 @@ class VerticalSession:
             for ep, w in zip(eps, workers):
                 self._recv_from_owner(ep, w, "warmup_done",
                                       timeout=warmup_timeout)
+            phase.close()
 
             # ---------------- the timed training region
             history: dict = {"train": [], "eval": []}
-            t0 = time.time()
+            t0 = time.perf_counter()
             t_warm = None     # end of step 0 (steady-state guard band)
             overhead_s = 0.0  # eval/sync/ckpt time, excluded from step cost
             metrics: dict = {}
@@ -1520,106 +1543,122 @@ class VerticalSession:
             fwd_next = 0        # next head_fwd seq to ship
             while t < total_steps:
               try:
-                if supervise and t % resync_every == 0 \
-                        and marker["last"] != t:
-                    mark(t)
-                if fwd_next == t:
-                    # step t's forward request (start or replay resume)
-                    send_fwd(get_idx(t), t)
-                    fwd_next = t + 1
-                if (not sequential and t + 1 < total_steps
-                        and fwd_next == t + 1):
-                    # the t+1 forward request leaves FIRST: it overlaps
-                    # the wire and the owners stage (not run) it until
-                    # their step-t update lands — FIFO keeps it exact
-                    send_fwd(get_idx(t + 1), t + 1)
-                    fwd_next = t + 2
-                idx_t = inflight.popleft()
-                # label staging runs while the cut chunks are on the wire
-                lab_t = np.asarray(labels[idx_t])
-                lab_chunks = [jnp.asarray(lab_t[m * bm:(m + 1) * bm])
-                              for m in range(M)]
-                if sequential:
-                    # synchronous baseline: one whole-batch exchange
-                    # through the fused one-pass trunk program; update
-                    # strictly before the grads leave, wait for every
-                    # owner's step, then request t+1
-                    cuts, owner_aux = recv_chunk(t)
-                    if masked:
-                        # recv_chunk already folded the ring sum; the
-                        # broadcast z-grad goes back to every owner
-                        parts, tg, zg = trunk_step(
-                            trunk_params, cuts, lab_chunks[0])
-                        cg = [zg] * len(eps)
-                    else:
-                        parts, tg, cg = trunk_step(
-                            trunk_params, jnp.stack(cuts), lab_chunks[0])
-                    trunk_params, trunk_state = trunk_update(
-                        trunk_params, trunk_state, tg, t)
-                    for p, ep in enumerate(eps):
-                        ep.send("cut_gradients",
-                                codec.encode(defend(cg[p], t, p)),
-                                seq=t)
-                    for ep, w in zip(eps, workers):
-                        self._recv_from_owner(ep, w, "step_done",
-                                              timeout=timeout)
-                    if t + 1 < total_steps and fwd_next == t + 1:
+                with span(spans.STEP, party=SCIENTIST, step=t):
+                    if supervise and t % resync_every == 0 \
+                            and marker["last"] != t:
+                        mark(t)
+                    if fwd_next == t:
+                        # step t's forward request (start or replay
+                        # resume)
+                        send_fwd(get_idx(t), t)
+                        fwd_next = t + 1
+                    if (not sequential and t + 1 < total_steps
+                            and fwd_next == t + 1):
+                        # the t+1 forward request leaves FIRST: it
+                        # overlaps the wire and the owners stage (not run)
+                        # it until their step-t update lands — FIFO keeps
+                        # it exact
                         send_fwd(get_idx(t + 1), t + 1)
                         fwd_next = t + 2
-                    parts_list = [parts]
-                else:
-                    # pipelined GPipe: each chunk's cut grads ship the
-                    # moment its cuts arrive; everything batch-wide —
-                    # trunk weight grads, the optimizer update, metric
-                    # folds — runs in the wire's shadow afterwards
-                    owner_aux = 0.0
-                    parts_list = []
-                    cut_cache = []
-                    for m in range(M):
-                        seq = t * M + m
-                        cuts, aux_m = recv_chunk(seq)
-                        owner_aux += aux_m
-                        cg, parts = cutgrad(trunk_params, cuts,
-                                            lab_chunks[m], denom,
-                                            inv_micro)
+                    idx_t = inflight.popleft()
+                    # label staging runs while the cut chunks are on the
+                    # wire
+                    with span(spans.LABEL_STAGE, party=SCIENTIST, step=t):
+                        lab_t = np.asarray(labels[idx_t])
+                        lab_chunks = [
+                            jnp.asarray(lab_t[m * bm:(m + 1) * bm])
+                            for m in range(M)]
+                    if sequential:
+                        # synchronous baseline: one whole-batch exchange
+                        # through the fused one-pass trunk program; update
+                        # strictly before the grads leave, wait for every
+                        # owner's step, then request t+1
+                        cuts, owner_aux = recv_chunk(t)
                         if masked:
-                            # cutgrad returned the broadcast z-grad
-                            cg = [cg] * len(eps)
+                            # recv_chunk already folded the ring sum; the
+                            # broadcast z-grad goes back to every owner
+                            parts, tg, zg = trunk_step(
+                                trunk_params, cuts, lab_chunks[0])
+                            cg = [zg] * len(eps)
+                        else:
+                            parts, tg, cg = trunk_step(
+                                trunk_params, jnp.stack(cuts),
+                                lab_chunks[0])
+                        trunk_params, trunk_state = trunk_update(
+                            trunk_params, trunk_state, tg, t)
                         for p, ep in enumerate(eps):
                             ep.send("cut_gradients",
-                                    codec.encode(defend(cg[p], seq, p)),
-                                    seq=seq)
-                        parts_list.append(parts)
-                        cut_cache.append((cuts, lab_chunks[m]))
-                    tg_acc = None
-                    for cuts, lab_m in cut_cache:
-                        tg = weightgrad(trunk_params, cuts, lab_m,
-                                        denom, inv_micro)
-                        tg_acc = tg if tg_acc is None else \
-                            _tree_add(tg_acc, tg)
-                    trunk_params, trunk_state = trunk_update(
-                        trunk_params, trunk_state, tg_acc, t)
-                parts_acc = parts_list[0]
-                for parts in parts_list[1:]:
-                    parts_acc = {k: parts_acc[k] + parts[k]
-                                 for k in parts}
-                metrics = dict(parts_acc)
-                if owner_aux and "aux" in metrics:
-                    # joint-path parity: heads aux + trunk aux
-                    metrics = {**metrics,
-                               "aux": metrics["aux"] + owner_aux}
-                if t == 0:
-                    t_warm = time.time()
+                                    codec.encode(defend(cg[p], t, p)),
+                                    seq=t)
+                        for ep, w in zip(eps, workers):
+                            self._recv_from_owner(ep, w, "step_done",
+                                                  timeout=timeout)
+                        if t + 1 < total_steps and fwd_next == t + 1:
+                            send_fwd(get_idx(t + 1), t + 1)
+                            fwd_next = t + 2
+                        parts_list = [parts]
+                    else:
+                        # pipelined GPipe: each chunk's cut grads ship the
+                        # moment its cuts arrive; everything batch-wide —
+                        # trunk weight grads, the optimizer update, metric
+                        # folds — runs in the wire's shadow afterwards
+                        owner_aux = 0.0
+                        parts_list = []
+                        cut_cache = []
+                        for m in range(M):
+                            seq = t * M + m
+                            cuts, aux_m = recv_chunk(seq)
+                            owner_aux += aux_m
+                            with span(spans.TRUNK_CUTGRAD, party=SCIENTIST,
+                                      step=t):
+                                cg, parts = cutgrad(trunk_params, cuts,
+                                                    lab_chunks[m], denom,
+                                                    inv_micro)
+                            if masked:
+                                # cutgrad returned the broadcast z-grad
+                                cg = [cg] * len(eps)
+                            with span(spans.CUT_GRAD_SEND, party=SCIENTIST,
+                                      step=t):
+                                for p, ep in enumerate(eps):
+                                    ep.send("cut_gradients", codec.encode(
+                                        defend(cg[p], seq, p)), seq=seq)
+                            parts_list.append(parts)
+                            cut_cache.append((cuts, lab_chunks[m]))
+                        tg_acc = None
+                        with span(spans.TRUNK_WEIGHTGRAD, party=SCIENTIST,
+                                  step=t):
+                            for cuts, lab_m in cut_cache:
+                                tg = weightgrad(trunk_params, cuts, lab_m,
+                                                denom, inv_micro)
+                                tg_acc = tg if tg_acc is None else \
+                                    _tree_add(tg_acc, tg)
+                        with span(spans.TRUNK_UPDATE, party=SCIENTIST,
+                                  step=t):
+                            trunk_params, trunk_state = trunk_update(
+                                trunk_params, trunk_state, tg_acc, t)
+                    parts_acc = parts_list[0]
+                    for parts in parts_list[1:]:
+                        parts_acc = {k: parts_acc[k] + parts[k]
+                                     for k in parts}
+                    metrics = dict(parts_acc)
+                    if owner_aux and "aux" in metrics:
+                        # joint-path parity: heads aux + trunk aux
+                        metrics = {**metrics,
+                                   "aux": metrics["aux"] + owner_aux}
+                    if t == 0:
+                        t_warm = time.perf_counter()
 
-                # ----------- bookkeeping (excluded from step timings)
-                tb = time.time()
-                self._train_bookkeeping(
-                    t, metrics, history, t0, epochs=epochs, steps=steps,
-                    steps_per_epoch=steps_per_epoch, log_every=log_every,
-                    verbose=verbose, ckpt_dir=ckpt_dir,
-                    ckpt_every=ckpt_every, sync=sync)
-                overhead_s += time.time() - tb
-                t += 1
+                    # ----------- bookkeeping (excluded from step timings)
+                    tb = time.perf_counter()
+                    with span(spans.BOOKKEEPING, party=SCIENTIST, step=t):
+                        self._train_bookkeeping(
+                            t, metrics, history, t0, epochs=epochs,
+                            steps=steps, steps_per_epoch=steps_per_epoch,
+                            log_every=log_every, verbose=verbose,
+                            ckpt_dir=ckpt_dir, ckpt_every=ckpt_every,
+                            sync=sync, scalars=_read_scalars)
+                    overhead_s += time.perf_counter() - tb
+                    t += 1
               except (OwnerFailure, FrameCorrupt) as e:
                 if not supervise:
                     raise
@@ -1627,7 +1666,8 @@ class VerticalSession:
                 inflight.clear()
                 fwd_next = t
 
-            wall_s = time.time() - t0
+            wall_s = time.perf_counter() - t0
+            phase.enter_context(span(spans.FIT_END, party=SCIENTIST))
             self._sync_split_params(workers, eps, trunk_params,
                                     timeout=timeout)
             if steps is not None and len(self._eval_idx):
@@ -1649,6 +1689,7 @@ class VerticalSession:
                 shutdown = getattr(w, "shutdown", None)
                 if shutdown is not None:    # process-backed handle
                     shutdown()
+            phase.close()
 
         # ------------------------------------- measured traffic accounting
         per_owner: Dict[str, dict] = {}

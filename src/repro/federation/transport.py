@@ -50,6 +50,8 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro.federation.spans import HOST_READ, WIRE_PACK, WIRE_UNPACK, span
+
 __all__ = ["Message", "Channel", "Endpoint", "ScopedEndpoint",
            "channel_pair", "Codec", "get_codec", "CODECS", "SPIN_WAIT_S",
            "spin_wait_s", "FrameCorrupt"]
@@ -138,23 +140,32 @@ def _wait_until(deadline: float, spin_s: float = SPIN_WAIT_S) -> None:
 # per-array ``tobytes`` allocation, no list-of-parts join.
 
 
-def _frame_entries(payload: Dict[str, np.ndarray]):
-    """Normalize payload values and precompute the exact frame size."""
+def _frame_entries(payload: Dict[str, np.ndarray], party: str = ""):
+    """Normalize payload values and precompute the exact frame size.  A
+    device array is read to the host in a ``vfl.host_read`` span of
+    ``party``."""
     entries = []
     size = 4
     for name, arr in payload.items():
-        arr = np.ascontiguousarray(np.asarray(arr))
+        if (isinstance(arr, (np.ndarray, np.generic))
+                or not hasattr(arr, "nbytes")):       # already on the host
+            arr = np.ascontiguousarray(np.asarray(arr))
+        else:                                         # a device array
+            with span(HOST_READ, party=party, bytes=arr.nbytes):
+                arr = np.ascontiguousarray(np.asarray(arr))
         nb, dt = name.encode(), arr.dtype.name.encode()
         size += 2 + len(nb) + 2 + len(dt) + 1 + 8 * arr.ndim + 8 + arr.nbytes
         entries.append((nb, dt, arr))
     return entries, size
 
 
-def _pack_into(payload: Dict[str, np.ndarray], buf: bytearray) -> int:
+def _pack_into(payload: Dict[str, np.ndarray], buf: bytearray,
+               party: str = "") -> int:
     """Pack ``{name: array}`` into ``buf`` (grown as needed), returning
     the number of bytes used.  ``buf`` is reusable scratch: callers
-    snapshot the used prefix before the next send."""
-    entries, size = _frame_entries(payload)
+    snapshot the used prefix before the next send.  ``party`` names the
+    sender in the spans of its device reads."""
+    entries, size = _frame_entries(payload, party)
     if len(buf) < size:
         buf.extend(b"\0" * (size - len(buf)))
     struct.pack_into("<I", buf, 0, len(entries))
@@ -299,11 +310,13 @@ class Channel:
         blob = None
         crc = None
         if self.serialize:
-            with self._send_lock:
-                used = _pack_into(payload, self._sendbuf)
-                blob = bytes(memoryview(self._sendbuf)[:used])
+            with span(WIRE_PACK, party=self.sender, peer=self.receiver,
+                      kind=kind, seq=seq):
+                with self._send_lock:
+                    used = _pack_into(payload, self._sendbuf, self.sender)
+                    blob = bytes(memoryview(self._sendbuf)[:used])
+                crc = zlib.crc32(blob) & 0xFFFFFFFF
             wb = used
-            crc = zlib.crc32(blob) & 0xFFFFFFFF
             payload = {"__blob__": blob}           # only bytes travel
         else:
             wb = pb                                # by-reference handoff
@@ -341,12 +354,14 @@ class Channel:
         if msg.not_before:
             _wait_until(msg.not_before, self.spin_s)
         if self.serialize:
-            blob = msg.payload["__blob__"]
-            if msg.crc is not None and (
-                    zlib.crc32(blob) & 0xFFFFFFFF) != msg.crc:
-                raise FrameCorrupt(msg.kind, msg.seq, self.sender,
-                                   self.receiver)
-            msg.payload = _unpack(blob)
+            with span(WIRE_UNPACK, party=self.receiver, peer=self.sender,
+                      kind=msg.kind, seq=msg.seq):
+                blob = msg.payload["__blob__"]
+                if msg.crc is not None and (
+                        zlib.crc32(blob) & 0xFFFFFFFF) != msg.crc:
+                    raise FrameCorrupt(msg.kind, msg.seq, self.sender,
+                                       self.receiver)
+                msg.payload = _unpack(blob)
         return msg
 
     def empty(self) -> bool:
