@@ -70,6 +70,7 @@ from repro.core.splitnn import (cut_layer_traffic, make_split_train_step,
 from repro.federation import batching, faults, spans, transport
 from repro.federation.parties import (DataOwner, DataScientist,
                                       OwnerComputeEndpoint, PrivacyError)
+from repro.federation.process_transport import ProcessEndpoint
 from repro.federation.registry import build_adapter
 from repro.federation.spans import SCIENTIST, span
 from repro.federation.supervisor import OwnerFailure, Supervisor
@@ -81,13 +82,41 @@ def _scalars(m):
 
 
 def _read_scalars(m):
-    """``_scalars`` of the split loop: each read from the device in a
-    ``vfl.host_read`` span."""
-    out = {}
-    for k, v in m.items():
-        with span(spans.HOST_READ, party=SCIENTIST, bytes=v.nbytes):
-            out[k] = float(v)
-    return out
+    """``_scalars`` of the split loop.  On channels that frame their
+    sends, the cut gradients' fetch has already brought the metrics to
+    the host with them (``_fit_split``), and nothing is read here; the
+    device arrays among them (``backend="direct"``) come over in one
+    ``jax.device_get``, in one ``vfl.host_read`` span."""
+    dev = {k: v for k, v in m.items() if isinstance(v, jax.Array)}
+    if dev:
+        m = {**m, **_fetch(dev)}
+    return _scalars(m)
+
+
+def _fetch(tree):
+    """``tree`` read to the host in one ``jax.device_get``, which starts
+    every copy before it waits on any, in one ``vfl.host_read`` span of
+    the scientist (``bytes``: each distinct array once)."""
+    leaves = {id(a): a.nbytes for a in jax.tree.leaves(tree)}
+    with span(spans.HOST_READ, party=SCIENTIST, bytes=sum(leaves.values())):
+        return jax.device_get(tree)
+
+
+def _device_nbytes(tree) -> int:
+    """The bytes ``tree``'s host arrays take on the device, in JAX's
+    canonical dtypes (int64 labels land as int32)."""
+    return sum(a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
+               for a in jax.tree.leaves(tree))
+
+
+def _serializes(ep) -> bool:
+    """Whether ``ep`` frames what it sends, reading every device array
+    in a payload to the host: the queue backend's channels
+    (``Channel.serialize``) and a process pipe do; the direct backend
+    hands arrays over by reference."""
+    if isinstance(ep, transport.Endpoint):
+        return ep.outbox.serialize
+    return isinstance(ep, ProcessEndpoint)
 
 
 def _tree_add(a, b):
@@ -1063,6 +1092,23 @@ class VerticalSession:
         update exactly once per step).  An explicit warmup round
         compiles every program on both sides before the timed region.
 
+        Each chunk crosses the scientist's host-device boundary once in
+        each direction.  In: right after its cuts arrive, the decoded
+        cuts (or the masked path's reconstructed sum) and the chunk's
+        labels go to the device in one ``jax.device_put``
+        (``vfl.host_stage``); ``cutgrad`` and ``weightgrad`` both take
+        those device arrays.  Out: on channels that frame their sends
+        (``Channel.serialize``, and process pipes), framing would read
+        every cut gradient to the host one by one anyway, so after
+        ``cutgrad`` one ``jax.device_get`` fetches the encoded
+        cut-gradient payloads together with the chunk's metric parts
+        (``_fetch``), and the sends and the bookkeeping read nothing.
+        On the direct backend the payloads stay device arrays, handed
+        over by reference, and the metrics are read in one batched
+        fetch in the bookkeeping (``_read_scalars``).  The transfers
+        change only in their grouping and moment: the programs, their
+        inputs' bits and the wire's bytes are the same.
+
         With the lossless codec, both schedules reproduce the joint
         program bit-for-bit whenever the adapter's head optimizer is
         elementwise-separable across owners (the paper's MLP/SGD case —
@@ -1276,8 +1322,8 @@ class VerticalSession:
 
         def recv_chunk(seq):
             """One microbatch chunk from every owner -> per-owner cut
-            tuple + the owners' summed aux scalar.  The cuts go into the
-            jitted trunk programs as-is (stacking happens in-program).
+            tuple + the owners' summed aux scalar, on the host (``stage``
+            puts them on the device; stacking happens in-program).
             Masked runs fold the owners' uint32 ring payloads instead:
             the return is the reconstructed int32 SUM — the scientist
             never materializes a per-owner activation."""
@@ -1297,8 +1343,29 @@ class VerticalSession:
                     if "aux" in m.payload:
                         aux += float(np.asarray(m.payload["aux"]).sum())
             if masked:
-                return jnp.asarray(masking.reconstruct(payloads)), aux
+                return masking.reconstruct(payloads), aux
             return tuple(cuts), aux
+
+        def stage(cuts, lab, step):
+            """A chunk's cuts and labels on the device, in one put."""
+            with span(spans.HOST_STAGE, party=SCIENTIST, step=step,
+                      bytes=_device_nbytes((cuts, lab))):
+                return jax.device_put((cuts, lab))
+
+        def grad_payloads(cg, parts, seq):
+            """Each owner's encoded cut gradient for chunk ``seq``, and
+            the chunk's metric parts: fetched to the host together in
+            one read where the channels frame their sends — before the
+            defences, which run on the host — else left on the
+            device."""
+            if all(_serializes(ep) for ep in eps):
+                if defend_on:
+                    cg, parts = _fetch((cg, parts))
+                else:
+                    return _fetch(
+                        ([codec.encode(g) for g in cg], parts))
+            return [codec.encode(defend(g, seq, p))
+                    for p, g in enumerate(cg)], parts
 
         # Party threads trade sub-millisecond messages; CPython's default
         # 5 ms GIL switch interval would let one party's pure-Python
@@ -1328,30 +1395,24 @@ class VerticalSession:
                         payloads.append(mm.payload)
                     else:
                         cuts.append(codec.decode(mm.payload))
-                lab_m = jnp.asarray(wlab[m * bm:(m + 1) * bm])
-                if masked:
-                    # all owners are generation 0 here, so their warmup
-                    # masks cancel and the fold is the true zsum —
-                    # compiles the masked trunk programs at real shapes
-                    zsum = jnp.asarray(masking.reconstruct(payloads))
-                    if sequential:
-                        _, _, zg = trunk_step(trunk_params, zsum, lab_m)
-                    else:
-                        zg, _ = cutgrad(trunk_params, zsum, lab_m,
-                                        denom, inv_micro)
-                        weightgrad(trunk_params, zsum, lab_m, denom,
-                                   inv_micro)
-                    zero = np.zeros_like(np.asarray(zg))
-                elif sequential:
-                    _, _, cg = trunk_step(trunk_params, jnp.stack(cuts),
-                                          lab_m)
-                    zero = np.zeros_like(np.asarray(cg[0]))
+                # staged as the steps stage theirs: device arrays in
+                # every program's warm-up call.  Masked: all owners are
+                # generation 0 here, so their warmup masks cancel and the
+                # fold is the true zsum — compiles the masked trunk
+                # programs at real shapes
+                cuts, lab_m = jax.device_put(
+                    (masking.reconstruct(payloads) if masked
+                     else tuple(cuts), wlab[m * bm:(m + 1) * bm]))
+                if sequential:
+                    _, _, cg = trunk_step(
+                        trunk_params, cuts if masked else jnp.stack(cuts),
+                        lab_m)
                 else:
-                    cg, _ = cutgrad(trunk_params, tuple(cuts), lab_m,
-                                    denom, inv_micro)
-                    weightgrad(trunk_params, tuple(cuts), lab_m, denom,
-                               inv_micro)
-                    zero = np.zeros_like(np.asarray(cg[0]))
+                    cg, _ = cutgrad(trunk_params, cuts, lab_m, denom,
+                                    inv_micro)
+                    weightgrad(trunk_params, cuts, lab_m, denom, inv_micro)
+                # masked: the broadcast z-grad
+                zero = np.zeros_like(np.asarray(cg if masked else cg[0]))
                 wzero = zero
                 for ep in eps:
                     ep.send("warmup_grads", codec.encode(zero), seq=m)
@@ -1561,29 +1622,26 @@ class VerticalSession:
                         send_fwd(get_idx(t + 1), t + 1)
                         fwd_next = t + 2
                     idx_t = inflight.popleft()
-                    # label staging runs while the cut chunks are on the
-                    # wire
+                    # the labels' gather runs while the cut chunks are on
+                    # the wire; each chunk's go to the device with its cuts
                     with span(spans.LABEL_STAGE, party=SCIENTIST, step=t):
                         lab_t = np.asarray(labels[idx_t])
-                        lab_chunks = [
-                            jnp.asarray(lab_t[m * bm:(m + 1) * bm])
-                            for m in range(M)]
                     if sequential:
                         # synchronous baseline: one whole-batch exchange
                         # through the fused one-pass trunk program; update
                         # strictly before the grads leave, wait for every
                         # owner's step, then request t+1
                         cuts, owner_aux = recv_chunk(t)
+                        cuts, lab_d = stage(cuts, lab_t, t)
                         if masked:
                             # recv_chunk already folded the ring sum; the
                             # broadcast z-grad goes back to every owner
                             parts, tg, zg = trunk_step(
-                                trunk_params, cuts, lab_chunks[0])
+                                trunk_params, cuts, lab_d)
                             cg = [zg] * len(eps)
                         else:
                             parts, tg, cg = trunk_step(
-                                trunk_params, jnp.stack(cuts),
-                                lab_chunks[0])
+                                trunk_params, jnp.stack(cuts), lab_d)
                         trunk_params, trunk_state = trunk_update(
                             trunk_params, trunk_state, tg, t)
                         for p, ep in enumerate(eps):
@@ -1609,21 +1667,26 @@ class VerticalSession:
                             seq = t * M + m
                             cuts, aux_m = recv_chunk(seq)
                             owner_aux += aux_m
+                            cuts, lab_m = stage(
+                                cuts, lab_t[m * bm:(m + 1) * bm], t)
                             with span(spans.TRUNK_CUTGRAD, party=SCIENTIST,
                                       step=t):
                                 cg, parts = cutgrad(trunk_params, cuts,
-                                                    lab_chunks[m], denom,
-                                                    inv_micro)
+                                                    lab_m, denom, inv_micro)
                             if masked:
                                 # cutgrad returned the broadcast z-grad
                                 cg = [cg] * len(eps)
                             with span(spans.CUT_GRAD_SEND, party=SCIENTIST,
                                       step=t):
-                                for p, ep in enumerate(eps):
-                                    ep.send("cut_gradients", codec.encode(
-                                        defend(cg[p], seq, p)), seq=seq)
+                                payloads, parts = grad_payloads(cg, parts,
+                                                                seq)
+                                for ep, payload in zip(eps, payloads):
+                                    ep.send("cut_gradients", payload,
+                                            seq=seq)
+                            # fetched parts add on the host, in chunk
+                            # order, in float32, as on the device
                             parts_list.append(parts)
-                            cut_cache.append((cuts, lab_chunks[m]))
+                            cut_cache.append((cuts, lab_m))
                         tg_acc = None
                         with span(spans.TRUNK_WEIGHTGRAD, party=SCIENTIST,
                                   step=t):

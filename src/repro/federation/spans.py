@@ -25,19 +25,23 @@ FIT_START = "vfl.fit_start"
 STEP = "vfl.step"
 #: the step's ``head_fwd`` index frames, one to each owner
 SEND_FWD = "vfl.send_fwd"
-#: the step's labels gathered and put on the device
+#: the step's labels gathered on the host
 LABEL_STAGE = "vfl.label_stage"
 #: waiting for one owner's cut (``peer``), its CRC, unpack and decode
 CUT_EXCHANGE = "vfl.cut_exchange"
+#: one chunk's cuts and labels put on the device in one call (``bytes``)
+HOST_STAGE = "vfl.host_stage"
 #: dispatch of the trunk's cut-gradient program
 TRUNK_CUTGRAD = "vfl.trunk_cutgrad"
 #: dispatch of the trunk's weight-gradient program
 TRUNK_WEIGHTGRAD = "vfl.trunk_weightgrad"
 #: dispatch of the trunk's optimizer update
 TRUNK_UPDATE = "vfl.trunk_update"
-#: the cut gradients to every owner: defence, codec encode and send
+#: the cut gradients to every owner: defence, codec encode, their fetch
+#: with the chunk's metrics where the channels frame them, and send
 CUT_GRAD_SEND = "vfl.cut_grad_send"
-#: the step's history record and the host reads of its loss scalars
+#: the step's history record, and its metrics' read where no fetch
+#: brought them (``backend="direct"``)
 BOOKKEEPING = "vfl.bookkeeping"
 #: after the last step: the parameter barrier, the owners' stop and join
 FIT_END = "vfl.fit_end"
@@ -51,10 +55,11 @@ CUT_ENCODE = "vfl.cut_encode"
 WIRE_PACK = "vfl.wire.pack"
 #: CRC check and unpack of one serialized receive (``kind``)
 WIRE_UNPACK = "vfl.wire.unpack"
-#: one device-to-host read of an array or a scalar (``bytes``)
+#: one device-to-host read of an array, or of a batch of arrays and
+#: scalars in one call (``bytes``, the total)
 HOST_READ = "vfl.host_read"
 
-SPANS = (FIT_START, STEP, SEND_FWD, LABEL_STAGE, CUT_EXCHANGE,
+SPANS = (FIT_START, STEP, SEND_FWD, LABEL_STAGE, CUT_EXCHANGE, HOST_STAGE,
          TRUNK_CUTGRAD, TRUNK_WEIGHTGRAD, TRUNK_UPDATE, CUT_GRAD_SEND,
          BOOKKEEPING, FIT_END, OWNER_FWD_REQUEST, OWNER_CUT_GRAD, CUT_ENCODE,
          WIRE_PACK, WIRE_UNPACK, HOST_READ)
