@@ -9,9 +9,10 @@ from __future__ import annotations
 import importlib
 from typing import Dict, List
 
-from repro.configs.base import (ArchConfig, MoEConfig, SSMConfig, ShapeConfig,
-                                SplitConfig, XLSTMConfig, SHAPES, TRAIN_4K,
-                                PREFILL_32K, DECODE_32K, LONG_500K)
+from repro.configs.base import (ArchConfig, MLAConfig, MoEConfig, SSMConfig,
+                                ShapeConfig, SplitConfig, XLSTMConfig,
+                                YaRNConfig, SHAPES, TRAIN_4K, PREFILL_32K,
+                                DECODE_32K, LONG_500K)
 
 _ARCH_MODULES: Dict[str, str] = {
     "zamba2-2.7b": "repro.configs.zamba2_2_7b",
@@ -24,6 +25,7 @@ _ARCH_MODULES: Dict[str, str] = {
     "whisper-tiny": "repro.configs.whisper_tiny",
     "nemotron-4-15b": "repro.configs.nemotron_4_15b",
     "llama3.2-3b": "repro.configs.llama3_2_3b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
 }
 
 
@@ -43,7 +45,8 @@ def get_shape(name: str) -> ShapeConfig:
 
 
 __all__ = [
-    "ArchConfig", "MoEConfig", "SSMConfig", "XLSTMConfig", "SplitConfig",
+    "ArchConfig", "MLAConfig", "MoEConfig", "SSMConfig", "XLSTMConfig",
+    "SplitConfig", "YaRNConfig",
     "ShapeConfig", "SHAPES", "TRAIN_4K", "PREFILL_32K", "DECODE_32K",
     "LONG_500K", "get_config", "get_shape", "list_archs",
 ]

@@ -33,6 +33,56 @@ class MoEConfig:
     # the cross-shard all-reduce of the dispatch buffer.  1 = paper-era
     # global dispatch (baseline).
     dispatch_groups: int = 1
+    # Routed-expert weights: renormalize the top-k (Mixtral, DeepSeekMoE)
+    # or keep the softmax probabilities as they are (DeepSeek-V2).
+    norm_topk: bool = True
+    # Balance loss: "switch" (f_e of first choices x P_e over the batch)
+    # or "seq" (DeepSeek-V2's sequence-wise loss over every top-k choice).
+    aux_kind: str = "switch"
+    # The share layer (expert parallelism by share): this chip holds the
+    # routed experts [held_first, held_first + n_held) of n_experts, still
+    # routes over all of them, and computes its own experts' part of the
+    # result for every choice that lands on them — no capacity, no dropped
+    # choice.  n_held = 0 keeps the capacity layer with every expert held.
+    n_held: int = 0
+    held_first: int = 0
+
+    @property
+    def share(self) -> bool:
+        return self.n_held > 0
+
+    @property
+    def n_local(self) -> int:
+        """Routed experts whose weights this layer holds."""
+        return self.n_held if self.share else self.n_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """DeepSeek-V2 multi-head latent attention (no query compression):
+    keys and values come up from a ``kv_lora_rank`` latent (RMS-normed),
+    and one rotary key of ``qk_rope_head_dim`` is shared by every head."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 sets it."""
+
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -132,6 +182,11 @@ class ArchConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     xlstm: Optional[XLSTMConfig] = None
+    mla: Optional[MLAConfig] = None        # attention blocks run MLA
+    yarn: Optional[YaRNConfig] = None      # rotary frequencies by YaRN
+    # leading layers with a dense FFN of width d_ff (DeepSeek's
+    # first_k_dense_replace); the MoE FFN applies from this layer on
+    n_dense_layers: int = 0
 
     # encoder-decoder (whisper): head = encoder, trunk = decoder.
     enc_dec: bool = False
@@ -159,6 +214,9 @@ class ArchConfig:
     remat: bool = True             # activation-checkpoint each super-block
 
     def __post_init__(self):
+        if self.mla is not None:
+            # q/k heads are nope + rope wide (values are v_head_dim)
+            object.__setattr__(self, "head_dim", self.mla.qk_head_dim)
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         if self.enc_dec:
@@ -225,6 +283,19 @@ class ArchConfig:
                 self.ssm, d_state=16, head_dim=32, chunk_size=32)
         if self.xlstm is not None:
             kw["xlstm"] = dataclasses.replace(self.xlstm, chunk_size=32)
+        if self.mla is not None:
+            kw["mla"] = MLAConfig(kv_lora_rank=64, qk_nope_head_dim=32,
+                                  qk_rope_head_dim=16, v_head_dim=32)
+        if self.moe is not None and self.moe.share:
+            n_exp = kw["moe"].n_experts
+            kw["moe"] = dataclasses.replace(
+                kw["moe"], n_held=min(self.moe.n_held, n_exp),
+                held_first=min(self.moe.held_first,
+                               n_exp - min(self.moe.n_held, n_exp)))
+        if self.n_dense_layers:
+            # one dense layer (each owner's head) and one after it
+            kw["n_dense_layers"] = 1
+            kw["n_layers"] = 2
         return dataclasses.replace(self, **kw)
 
     def param_count(self, active_only: bool = False) -> int:
@@ -240,6 +311,14 @@ class ArchConfig:
         per_layer = {}
 
         def attn_params():
+            if self.mla is not None:
+                m, h = self.mla, self.n_heads
+                return (d * h * m.qk_head_dim                    # W_q
+                        + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+                        + m.kv_lora_rank                         # latent norm
+                        + m.kv_lora_rank * h * (m.qk_nope_head_dim
+                                                + m.v_head_dim)
+                        + h * m.v_head_dim * d)                  # W_o
             return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
 
         def mlp_params(d_ff):
@@ -247,15 +326,24 @@ class ArchConfig:
                 return 3 * d * d_ff
             return 2 * d * d_ff
 
+        n_dense = 0
         for kind in set(self.block_pattern):
             if kind.startswith("attn") or kind == "shared_attn":
                 p = attn_params()
                 if self.moe is not None:
                     e = self.moe
-                    routed = e.top_k if active_only else e.n_experts
-                    p += routed * 3 * d * e.d_expert
+                    # active: the top-k of the held experts' share
+                    routed = (e.top_k * e.n_local / e.n_experts
+                              if active_only else e.n_local)
+                    p += int(routed * 3 * d * e.d_expert)
                     p += e.n_shared * 3 * d * max(e.d_shared, e.d_expert)
                     p += d * e.n_experts  # router
+                    if self.n_dense_layers:
+                        # leading dense layers: their FFN in place of the
+                        # MoE FFN
+                        moe_ffn = p - attn_params()
+                        n_dense = self.n_dense_layers * (
+                            mlp_params(self.d_ff) - moe_ffn)
                 elif self.d_ff:
                     p += mlp_params(self.d_ff)
             elif kind == "mamba2":
@@ -273,6 +361,7 @@ class ArchConfig:
             per_layer[kind] = p
 
         n_units = self.n_superblocks
+        total += n_dense
         shared_counted = False
         for kind in self.block_pattern:
             if kind == "shared_attn":

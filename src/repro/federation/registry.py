@@ -321,6 +321,13 @@ class SplitLMAdapter(_ProgramCache):
         return (batch_size, feature_shape[0], self.model.k)
 
     def make_engine(self, params, **engine_kw):
+        if self.cfg.mla is not None:
+            # SplitModel's prefill/decode keep MLA's per-head keys and
+            # values, 16x the latent a served MLA model caches per token
+            raise ValueError(
+                f"{self.cfg.name}: serving multi-head latent attention "
+                "needs a latent KV cache, which the serving engine does "
+                "not have; this model trains (fit) but is not served")
         from repro.launch.engine import ServingEngine   # avoid import cycle
         return ServingEngine(self.model, params, **engine_kw)
 
@@ -371,9 +378,10 @@ class SplitLMAdapter(_ProgramCache):
             def trunk_step(tp, cut, labels):
                 def f(tp_, cut_):
                     z = model.combine(cut_.astype(cdt))
-                    logits, _, aux_t = model.trunk_forward(tp_, z)
+                    logits, _, aux_t, stats = model.trunk_forward(
+                        tp_, z, with_stats=True)
                     ce = model.ce_loss(logits, labels)
-                    return ce + aux_t, {"loss": ce, "aux": aux_t}
+                    return ce + aux_t, {"loss": ce, "aux": aux_t, **stats}
 
                 (_, metrics), (tg, cg) = jax.value_and_grad(
                     f, argnums=(0, 1), has_aux=True)(tp, cut)
@@ -389,18 +397,22 @@ class SplitLMAdapter(_ProgramCache):
         and the trunk aux loss contributes ``aux / n_micro``, so summing
         metric parts and grads across chunks reproduces full-batch
         semantics; per-owner clipping already makes the LM path
-        tolerance- (not bit-) equivalent to the fused joint program."""
+        tolerance- (not bit-) equivalent to the fused joint program.
+        With a share layer in the trunk, the parts also hold its
+        counters (``models.moe.MOE_STATS``), summed over its blocks: they
+        ride the chunk's one fetch with the loss scalars."""
         model = self.model
         cdt = jnp.dtype(model.cfg.compute_dtype)
 
         def build():
             def chunk_loss(tp, cuts, labels, denom, inv_micro):
                 z = model.combine(jnp.stack(cuts).astype(cdt))
-                logits, _, aux_t = model.trunk_forward(tp, z)
+                logits, _, aux_t, stats = model.trunk_forward(
+                    tp, z, with_stats=True)
                 ce = model.ce_loss(logits, labels) \
                     * labels.shape[0] / denom
                 aux = aux_t * inv_micro
-                return ce + aux, {"loss": ce, "aux": aux}
+                return ce + aux, {"loss": ce, "aux": aux, **stats}
 
             def cutgrad(tp, cuts, labels, denom, inv_micro):
                 (_, parts), cg = jax.value_and_grad(

@@ -123,6 +123,24 @@ def _tree_add(a, b):
     return jax.tree.map(lambda x, y: x + y, a, b)
 
 
+def _moe_stats(records) -> Optional[dict]:
+    """The trunk's share-layer counters (``models.moe.MOE_STATS``) over
+    the recorded steps, as the steps' metric parts brought them to the
+    host; None where no record holds them.  ``rows_per_routed``: rows
+    of the held experts' buffer (``T * min(K, H)`` a layer) over the
+    token choices routed to held experts (1 = no padding); ``peak_over_mean``: the
+    layers' largest held-expert loads over their mean loads."""
+    recs = [r for r in records if "moe_routed" in r]
+    if not recs:
+        return None
+    tot = {k: float(sum(r[f"moe_{k}"] for r in recs))
+           for k in ("routed", "rows", "peak", "mean")}
+    return {"steps": len(recs), "routed": tot["routed"],
+            "rows": tot["rows"],
+            "rows_per_routed": tot["rows"] / max(tot["routed"], 1.0),
+            "peak_over_mean": tot["peak"] / max(tot["mean"], 1e-9)}
+
+
 #: leaked-actor accounting: party threads that outlived their join
 #: deadline (process-wide — a wedged actor sleeping through its stop is
 #: the common producer; tests reset this between cases)
@@ -1695,6 +1713,9 @@ class VerticalSession:
                                                 denom, inv_micro)
                                 tg_acc = tg if tg_acc is None else \
                                     _tree_add(tg_acc, tg)
+                                # the last step's weight grads must not
+                                # stay live beside the next weightgrad's
+                                del tg
                         with span(spans.TRUNK_UPDATE, party=SCIENTIST,
                                   step=t):
                             trunk_params, trunk_state = trunk_update(
@@ -1812,6 +1833,7 @@ class VerticalSession:
             // max(total_steps, 1),
             "recoveries": len(self.recovery_events),
             "supervisor": dict(sup.stats) if sup is not None else None,
+            "moe": _moe_stats(history["train"]),
         }
 
         final = dict(history["train"][-1]) if history["train"] else {}

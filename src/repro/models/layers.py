@@ -101,10 +101,39 @@ def rope_freqs(head_dim: int, theta: float):
                             / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, head_dim); positions: broadcastable to (..., S)."""
+def yarn_correction_range(dim: int, theta: float, yarn):
+    """YaRN's (low, high) frequency slots: below ``low`` a slot keeps its
+    frequency, above ``high`` it is divided by the factor (DeepSeek-V2's
+    ``yarn_find_correction_range``)."""
+    def slot(n_rot):
+        return (dim * np.log(yarn.original_max_position_embeddings
+                             / (n_rot * 2 * np.pi))) / (2 * np.log(theta))
+    low = int(np.floor(slot(yarn.beta_fast)))
+    high = int(np.ceil(slot(yarn.beta_slow)))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * float(np.log(factor)) + 1.0
+
+
+def yarn_freqs(dim: int, theta: float, yarn):
+    """YaRN rotary frequencies (dim/2,): a linear ramp over the correction
+    range from the plain frequencies to those divided by ``factor``."""
+    plain = rope_freqs(dim, theta)
+    low, high = yarn_correction_range(dim, theta, yarn)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return plain * (1.0 - ramp) + plain / yarn.factor * ramp
+
+
+def apply_rope(x, positions, theta: float, freqs=None):
+    """x: (..., S, H, head_dim); positions: broadcastable to (..., S).
+    ``freqs`` (head_dim/2,) replaces the plain ``theta`` frequencies
+    (YaRN).  Pairs are (i, i + head_dim/2)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                         # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                     # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     ang = ang[..., None, :]                               # (..., S, 1, hd/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)

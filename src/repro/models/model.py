@@ -60,6 +60,18 @@ class SplitModel:
             self.n_trunk_units = n_units - cut
             self.head_pattern = cfg.block_pattern
             self.trunk_pattern = cfg.block_pattern
+        # FFN kind of each segment's blocks (None: the config's).  With
+        # leading dense layers, the cut falls at the dense/MoE boundary:
+        # the heads hold the dense layers, the trunk the MoE ones.
+        self.head_ffn = self.trunk_ffn = None
+        if cfg.n_dense_layers:
+            if (cfg.enc_dec or len(cfg.block_pattern) != 1
+                    or self.n_head_units != cfg.n_dense_layers):
+                raise ValueError(
+                    f"{cfg.name}: the cut (cut_layer={sp.cut_layer}) must "
+                    f"fall at the dense/MoE boundary (n_dense_layers="
+                    f"{cfg.n_dense_layers}) of a one-kind block pattern")
+            self.head_ffn, self.trunk_ffn = "dense", "moe"
         self.k = sp.cut_dim if sp.cut_dim > 0 else cfg.d_model
 
     # ------------------------------------------------------------------ init
@@ -68,7 +80,8 @@ class SplitModel:
         cfg = self.cfg
         ks = jax.random.split(key, 4)
         p: Params = {"blocks": transformer.stack_init(
-            ks[0], cfg, self.n_head_units, self.head_pattern, _pdtype(cfg))}
+            ks[0], cfg, self.n_head_units, self.head_pattern, _pdtype(cfg),
+            ffn=self.head_ffn)}
         if cfg.modality == "text":
             p["embed"] = layers.embed_init(ks[1], cfg.vocab, cfg.d_model,
                                            _pdtype(cfg))
@@ -97,7 +110,8 @@ class SplitModel:
 
         ks = jax.random.split(kt, 4)
         trunk: Params = {"blocks": transformer.stack_init(
-            ks[0], cfg, self.n_trunk_units, self.trunk_pattern, _pdtype(cfg))}
+            ks[0], cfg, self.n_trunk_units, self.trunk_pattern, _pdtype(cfg),
+            ffn=self.trunk_ffn)}
         if cfg.split.cut_dim > 0:
             trunk["in_proj"] = layers.dense_init(ks[1], self.k, cfg.d_model,
                                                  _pdtype(cfg))
@@ -162,7 +176,7 @@ class SplitModel:
             hp["blocks"], x, cfg=cfg, pattern=self.head_pattern,
             positions=positions, caches=caches, pos=pos,
             swa_override=swa_override,
-            bidir=cfg.enc_dec and cfg.enc_bidirectional)
+            bidir=cfg.enc_dec and cfg.enc_bidirectional, ffn=self.head_ffn)
         if cfg.split.cut_dim > 0:
             x = layers.dense_apply(hp["cut_proj"], x)
         return x, new_caches, aux
@@ -245,11 +259,13 @@ class SplitModel:
     # ---------------------------------------------------------- trunk pass
 
     def trunk_forward(self, trunk, z, *, caches=None, pos=None, enc_out=None,
-                      dec_tokens=None, swa_override=None):
+                      dec_tokens=None, swa_override=None,
+                      with_stats: bool = False):
         """z: combined cut (B, S, k) (or enc output for enc_dec).
 
         enc_dec: trunk is the whisper decoder over ``dec_tokens`` with
-        cross-attention to z.  Returns (logits, caches, aux)."""
+        cross-attention to z.  Returns (logits, caches, aux), and the
+        share layer's counters (``{}`` without one) when ``with_stats``."""
         cfg = self.cfg
         if cfg.split.cut_dim > 0:
             z = layers.dense_apply(trunk["in_proj"], z)
@@ -268,16 +284,18 @@ class SplitModel:
             base = off + jnp.arange(S)
             positions = (jnp.stack([base] * 3, -1) if cfg.rope == "mrope"
                          else base)
-        x, new_caches, aux = transformer.stack_apply(
+        x, new_caches, aux, stats = transformer.stack_apply(
             trunk["blocks"], x, cfg=cfg, pattern=self.trunk_pattern,
             positions=positions, caches=caches, pos=pos, enc_out=enc_out,
-            swa_override=swa_override)
+            swa_override=swa_override, ffn=self.trunk_ffn, with_stats=True)
         x = layers.norm_apply(trunk["out_norm"], x, cfg.norm, cfg.norm_eps)
         x = constrain(x, "trunk_hidden")
         logits = layers.dense_apply(trunk["lm_head"],
                                     x.astype(jnp.float32))
         logits = layers.softcap(logits, cfg.logit_softcap)
         logits = constrain(logits, "logits")
+        if with_stats:
+            return logits, new_caches, aux, stats
         return logits, new_caches, aux
 
     # ------------------------------------------------------------- forward

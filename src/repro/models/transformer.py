@@ -25,16 +25,31 @@ def _has_ffn(cfg) -> bool:
     return cfg.moe is not None or (cfg.d_ff > 0 and cfg.mlp != "none")
 
 
-def _ffn_init(key, cfg, dtype):
-    if cfg.moe is not None:
+def _use_moe(cfg, ffn) -> bool:
+    """``ffn``: "moe" | "dense" | None (MoE wherever the config has it)."""
+    return cfg.moe is not None and ffn != "dense"
+
+
+def _ffn_init(key, cfg, dtype, ffn=None):
+    if _use_moe(cfg, ffn):
         return moe_mod.moe_init(key, cfg.d_model, cfg.moe, cfg.mlp, dtype)
     return mlp_mod.mlp_init(key, cfg.d_model, cfg.d_ff, cfg.mlp, dtype)
 
 
-def _ffn_apply(params, x, cfg):
-    if cfg.moe is not None:
-        return moe_mod.moe_apply(params, x, cfg.moe, cfg.mlp)
-    return mlp_mod.mlp_apply(params, x, cfg.mlp), 0.0
+def _ffn_apply(params, x, cfg, ffn=None):
+    """(out, aux, share-layer counters or {})."""
+    if _use_moe(cfg, ffn):
+        return moe_mod.moe_apply(params, x, cfg.moe, cfg.mlp,
+                                 with_stats=True)
+    return mlp_mod.mlp_apply(params, x, cfg.mlp), 0.0, {}
+
+
+def stats_zero(cfg, ffn=None) -> dict:
+    """The counters a stack of this FFN kind accumulates (the share
+    layer's; none for the others)."""
+    if _use_moe(cfg, ffn) and cfg.moe.share:
+        return moe_mod.moe_stats_zero()
+    return {}
 
 
 # ---------------------------------------------------------------------------
@@ -42,7 +57,7 @@ def _ffn_apply(params, x, cfg):
 # ---------------------------------------------------------------------------
 
 
-def block_init(key, cfg, kind: str, dtype=jnp.float32):
+def block_init(key, cfg, kind: str, dtype=jnp.float32, ffn=None):
     d = cfg.d_model
     ks = jax.random.split(key, 6)
     p = {"norm1": layers.norm_init(d, cfg.norm, dtype)}
@@ -53,7 +68,7 @@ def block_init(key, cfg, kind: str, dtype=jnp.float32):
             p["xattn"] = attention.attn_init(ks[1], cfg, dtype)
         if _has_ffn(cfg):
             p["norm2"] = layers.norm_init(d, cfg.norm, dtype)
-            p["ffn"] = _ffn_init(ks[2], cfg, dtype)
+            p["ffn"] = _ffn_init(ks[2], cfg, dtype, ffn)
         if cfg.post_block_norm:
             p["post1"] = layers.norm_init(d, cfg.norm, dtype)
             if _has_ffn(cfg):
@@ -73,8 +88,11 @@ def block_cache_init(batch: int, cfg, kind: str, s_max: int,
                      dtype=jnp.bfloat16, window_slots: int = 0):
     if kind in ("attn:global", "attn:local", "shared_attn", "dec"):
         s_eff = min(s_max, window_slots) if window_slots else s_max
+        # MLA: per-head keys and values as attention takes them (values
+        # v_head_dim wide), not the latent
+        v_dim = cfg.mla.v_head_dim if cfg.mla is not None else 0
         return attention.init_kv_cache(batch, s_eff, cfg.n_kv_heads,
-                                       cfg.head_dim, dtype)
+                                       cfg.head_dim, dtype, v_dim=v_dim)
     if kind == "mamba2":
         return ssm.mamba2_cache_init(batch, cfg, jnp.float32)
     if kind == "slstm":
@@ -86,9 +104,9 @@ def block_cache_init(batch: int, cfg, kind: str, s_max: int,
 
 def block_apply(params, x, *, cfg, kind: str, positions=None,
                 attn_kind: str = "causal", window: int = 0, cache=None,
-                pos=None, enc_out=None, chunk: int = 1024):
-    """Returns (x_out, new_cache, aux_loss)."""
-    aux = 0.0
+                pos=None, enc_out=None, chunk: int = 1024, ffn=None):
+    """Returns (x_out, new_cache, aux_loss, share-layer counters or {})."""
+    aux, stats = 0.0, {}
     h = layers.norm_apply(params["norm1"], x, cfg.norm, cfg.norm_eps)
     if kind in ("attn:global", "attn:local", "shared_attn", "dec"):
         a, new_cache = attention.attn_apply(
@@ -105,7 +123,7 @@ def block_apply(params, x, *, cfg, kind: str, positions=None,
             x = x + a
         if _has_ffn(cfg):
             h = layers.norm_apply(params["norm2"], x, cfg.norm, cfg.norm_eps)
-            f, aux = _ffn_apply(params["ffn"], h, cfg)
+            f, aux, stats = _ffn_apply(params["ffn"], h, cfg, ffn)
             if cfg.post_block_norm:
                 f = layers.norm_apply(params["post2"], f, cfg.norm,
                                       cfg.norm_eps)
@@ -121,7 +139,7 @@ def block_apply(params, x, *, cfg, kind: str, positions=None,
         x = x + y.astype(x.dtype)
     else:
         raise ValueError(kind)
-    return x, new_cache, aux
+    return x, new_cache, aux, stats
 
 
 # ---------------------------------------------------------------------------
@@ -129,13 +147,16 @@ def block_apply(params, x, *, cfg, kind: str, positions=None,
 # ---------------------------------------------------------------------------
 
 
-def stack_init(key, cfg, n_units: int, pattern=None, dtype=jnp.float32):
-    """Returns {"units": unit-stacked params, "shared": shared params}."""
+def stack_init(key, cfg, n_units: int, pattern=None, dtype=jnp.float32,
+               ffn=None):
+    """Returns {"units": unit-stacked params, "shared": shared params}.
+    ``ffn``: "dense" | "moe" | None (the config's) for every block."""
     pattern = pattern if pattern is not None else cfg.block_pattern
     shared = {}
     if "shared_attn" in pattern:
         key, sk = jax.random.split(key)
-        shared["shared_attn"] = block_init(sk, cfg, "shared_attn", dtype)
+        shared["shared_attn"] = block_init(sk, cfg, "shared_attn", dtype,
+                                           ffn)
 
     def unit_init(k):
         ks = jax.random.split(k, len(pattern))
@@ -144,7 +165,7 @@ def stack_init(key, cfg, n_units: int, pattern=None, dtype=jnp.float32):
             if kind == "shared_attn":
                 unit[f"b{i}"] = {}        # params live in `shared`
             else:
-                unit[f"b{i}"] = block_init(ks[i], cfg, kind, dtype)
+                unit[f"b{i}"] = block_init(ks[i], cfg, kind, dtype, ffn)
         return unit
 
     unit_keys = jax.random.split(key, n_units)
@@ -182,18 +203,22 @@ def stack_cache_init(batch: int, cfg, n_units: int, s_max: int,
 
 def stack_apply(params, x, *, cfg, pattern=None, positions=None,
                 caches=None, pos=None, enc_out=None, chunk: int = 1024,
-                swa_override: Optional[int] = None, bidir: bool = False):
-    """Apply all super-blocks.  Returns (x, new_caches, aux_total).
+                swa_override: Optional[int] = None, bidir: bool = False,
+                ffn=None, with_stats: bool = False):
+    """Apply all super-blocks.  Returns (x, new_caches, aux_total), and
+    the share layer's counters summed over the blocks (``{}`` for other
+    FFNs) as a fourth element when ``with_stats``.
 
     ``swa_override``: when set, every attn:global runs as sliding-window
     with this window (the explicit long-context variant, see DESIGN.md).
     ``bidir``: bidirectional self-attention (whisper encoder).
+    ``ffn``: the blocks' FFN kind, as ``stack_init`` took it.
     """
     pattern = pattern if pattern is not None else cfg.block_pattern
     shared = params["shared"]
 
     def superblock(x_aux, unit_params, unit_caches):
-        x, aux = x_aux
+        x, aux, stats = x_aux
         new_caches = {}
         for i, kind in enumerate(pattern):
             bp = (shared["shared_attn"] if kind == "shared_attn"
@@ -209,30 +234,33 @@ def stack_apply(params, x, *, cfg, pattern=None, positions=None,
                 attn_kind, window = "bidir", 0
             if kind == "dec" and enc_out is None:
                 raise ValueError("dec block needs enc_out")
-            x, nc, aux_i = block_apply(
+            x, nc, aux_i, stats_i = block_apply(
                 bp, x, cfg=cfg, kind=kind, positions=positions,
                 attn_kind=attn_kind, window=window, cache=cache_i, pos=pos,
-                enc_out=enc_out, chunk=chunk)
+                enc_out=enc_out, chunk=chunk, ffn=ffn)
             new_caches[f"b{i}"] = nc
             aux = aux + aux_i
-        return (x, aux), new_caches
+            stats = {k: v + stats_i.get(k, 0.0) for k, v in stats.items()}
+        return (x, aux, stats), new_caches
 
     if cfg.remat:
         superblock = jax.checkpoint(superblock)
 
-    aux0 = jnp.zeros((), jnp.float32)
+    carry0 = (x, jnp.zeros((), jnp.float32), stats_zero(cfg, ffn))
     if caches is None:
         def body(carry, unit_params):
             carry, _ = superblock(carry, unit_params, None)
             return carry, None
-        (x, aux), _ = jax.lax.scan(body, (x, aux0), params["units"])
-        return x, None, aux
+        (x, aux, stats), _ = jax.lax.scan(body, carry0, params["units"])
+        new_caches = None
+    else:
+        def body(carry, xs):
+            unit_params, unit_caches = xs
+            carry, new_caches = superblock(carry, unit_params, unit_caches)
+            return carry, new_caches
 
-    def body(carry, xs):
-        unit_params, unit_caches = xs
-        carry, new_caches = superblock(carry, unit_params, unit_caches)
-        return carry, new_caches
-
-    (x, aux), new_caches = jax.lax.scan(body, (x, aux0),
-                                        (params["units"], caches))
+        (x, aux, stats), new_caches = jax.lax.scan(
+            body, carry0, (params["units"], caches))
+    if with_stats:
+        return x, new_caches, aux, stats
     return x, new_caches, aux

@@ -23,6 +23,37 @@ def test_chunked_equals_direct():
     np.testing.assert_allclose(direct, chunked, atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("kind, window, softcap, hv, bwd_chunk", [
+    ("causal", 0, 0.0, 16, 512),
+    ("causal", 0, 0.0, 24, 32),
+    ("local", 40, 0.0, 16, 32),
+    ("causal", 0, 30.0, 16, 512),
+    ("bidir", 0, 0.0, 24, 32),
+])
+def test_chunked_gradients_equal_direct(monkeypatch, kind, window, softcap,
+                                        hv, bwd_chunk):
+    """The chunked path's own backward (recomputed chunk by chunk from
+    each row's log-sum-exp, in KV steps of ``BWD_CHUNK``) gives autodiff's
+    gradients of the direct path: GQA, a value width of its own (as in
+    MLA) and a given scale."""
+    from repro.models import attention as attention_mod
+    monkeypatch.setattr(attention_mod, "BWD_CHUNK", bwd_chunk)
+    B, S, nh, nkv, hd = 2, 256, 4, 2, 16
+    q, k, _ = _qkv(B, S, S, nh, nkv, hd)
+    v = jnp.asarray(RNG.normal(size=(B, S, nkv, hv)), jnp.float32)
+    w = jnp.asarray(RNG.normal(size=(B, S, nh, hv)), jnp.float32)
+
+    def loss(chunk):
+        return lambda q, k, v: jnp.sum(w * attention(
+            q, k, v, kind=kind, window=window, softcap=softcap, chunk=chunk,
+            scale=0.3))
+
+    direct = jax.grad(loss(4096), argnums=(0, 1, 2))(q, k, v)
+    chunked = jax.grad(loss(64), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(direct, chunked):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
 def test_causal_mask_blocks_future():
     """Changing future tokens must not change past outputs."""
     q, k, v = _qkv(1, 64, 64, 2, 2, 16)
